@@ -1,0 +1,59 @@
+"""The command's contract where no chip is: it exits nonzero and prints no
+result, also in a directory holding only the benchmark's files."""
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+ROOT = BENCH.parents[1]
+ARGS = ["--workload", "train-rdp-p0.5", "--seed", str(2**31 + 5),
+        "--seconds", "1", "--trace", "0"]
+
+
+def _run(cwd: Path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    return subprocess.run(
+        [sys.executable, "benchmarks/chip/run.py", *ARGS], cwd=cwd, env=env,
+        capture_output=True, text=True, timeout=300)
+
+
+def _no_result(out: str) -> bool:
+    for line in out.splitlines():
+        try:
+            if "metrics" in json.loads(line):
+                return False
+        except ValueError:
+            pass
+    return True
+
+
+def test_without_a_chip_exits_nonzero():
+    p = _run(ROOT)
+    assert p.returncode != 0, p.stdout
+    assert _no_result(p.stdout)
+    assert "no accelerator" in p.stderr
+
+
+def test_benchmark_files_alone_exit_nonzero(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(BENCH, tmp_path / "benchmarks" / "chip",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    p = _run(tmp_path)
+    assert p.returncode != 0, p.stdout
+    assert _no_result(p.stdout)
+
+
+def test_every_name_resolves_to_files():
+    import run as R
+    bench = R.load_json(ROOT / "BENCHMARK.json")
+    for w in bench["workloads"]:
+        cell = R.Cell(bench, w["name"])
+        assert cell.end_to_end and cell.per_layer
+        for m in cell.per_layer:
+            assert (BENCH / "metrics" / f"{m['name']}.py").exists()
+    for c in bench["configs"]:
+        assert (ROOT / c["file"]).exists()
