@@ -20,6 +20,11 @@ no recompile across biases.
 Block sizes default to (128, 128, 512): the pattern-dim block is pinned to the
 128-lane group granularity (a kept group is one lane-aligned block); the
 contraction block is larger to amortize the MXU pipeline.
+
+Each ``pallas_call`` is named after its function (``name=``): the HLO
+instruction, and so a device trace, names the kernel by it
+(``rdp_matmul_cols.40``) whatever the call site is, which is how a trace
+reduction finds the pattern kernels.
 """
 from __future__ import annotations
 
@@ -111,6 +116,7 @@ def rdp_matmul_cols(a: jax.Array, w: jax.Array, b: jax.Array, *, dp: int,
 
     return pl.pallas_call(
         kern,
+        name="rdp_matmul_cols",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -154,6 +160,7 @@ def rdp_matmul_rows(a_compact: jax.Array, w: jax.Array, b: jax.Array, *,
 
     return pl.pallas_call(
         kern,
+        name="rdp_matmul_rows",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

@@ -24,7 +24,8 @@ All four accumulate in f32 VMEM scratch over the contraction grid dim and
 share the forward kernels' contracts: the bias is a scalar-prefetch operand
 (one compiled kernel per ``dp`` bucket, no recompile across biases), block
 sizes are fitted with ``_fit_block``, and the compact/pattern dim is pinned
-to lane-aligned blocks.  ``interpret=True`` runs them on CPU.
+to lane-aligned blocks.  ``interpret=True`` runs them on CPU.  Like the
+forward kernels, each ``pallas_call`` carries its function's name.
 """
 from __future__ import annotations
 
@@ -65,6 +66,7 @@ def rdp_cols_dgrad(dc: jax.Array, w: jax.Array, b: jax.Array, *, dp: int,
 
     return pl.pallas_call(
         kern,
+        name="rdp_cols_dgrad",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -109,6 +111,7 @@ def rdp_cols_wgrad(a: jax.Array, dc: jax.Array, *, dp: int,
 
     return pl.pallas_call(
         kern,
+        name="rdp_cols_wgrad",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda k, j, i: (i, k)),
@@ -148,6 +151,7 @@ def rdp_rows_dgrad(dc: jax.Array, w: jax.Array, b: jax.Array, *, dp: int,
 
     return pl.pallas_call(
         kern,
+        name="rdp_rows_dgrad",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -190,6 +194,7 @@ def rdp_rows_wgrad(a_compact: jax.Array, dc: jax.Array, *, dp: int,
 
     return pl.pallas_call(
         kern,
+        name="rdp_rows_wgrad",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, block), lambda k, j, i: (i, k)),

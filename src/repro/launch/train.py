@@ -78,15 +78,14 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="write a Chrome-trace/Perfetto JSONL of per-step "
-                         "spans (data/dispatch/compile/train_step) here")
+                    help="write a Chrome-trace/Perfetto JSONL of the "
+                         "trainer's train.* phase spans here")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the final metrics-registry snapshot "
                          "(JSONL; use .prom suffix for Prometheus text)")
     ap.add_argument("--warm-start", action="store_true",
                     help="precompile every plan bucket before step 0 "
-                         "(also gauges per-bucket FLOPs/bytes from the "
-                         "compiled HLO and freezes the recompile watchdog)")
+                         "and freeze the recompile watchdog")
     ap.add_argument("--online-search", action="store_true",
                     help="re-run Alg. 1 during training (core.online_search"
                          ".OnlineSearch): per-layer K distributions drift "

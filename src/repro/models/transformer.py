@@ -270,37 +270,39 @@ def batch_logical_axes(cfg: ModelConfig, batch) -> dict:
 # forward (train / prefill trunk)
 # --------------------------------------------------------------------------
 
+def _attn(cfg, lp, x, pat, layer_idx, window):
+    """The attention sub-layer and its pre-norm, under the ``attn`` scope
+    (a device trace puts the time of every op it holds, forward and
+    backward, down to attention)."""
+    with jax.named_scope("attn"):
+        h = L.rms_norm(lp["norm1"], x, cfg.norm_eps)
+        if cfg.mla:
+            return L.mla_block(lp["attn"], h, n_heads=cfg.n_heads,
+                               qk_nope=cfg.qk_nope, qk_rope=cfg.qk_rope,
+                               v_dim=cfg.v_head_dim,
+                               rope_theta=cfg.rope_theta,
+                               chunk=cfg.attn_chunk)
+        return L.attention_block(lp["attn"], h, n_heads=cfg.n_heads,
+                                 n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
+                                 rope_theta=cfg.rope_theta, window=window,
+                                 chunk=cfg.attn_chunk,
+                                 pat=_attn_pat(cfg, pat), layer=layer_idx)
+
+
+def _ffn(cfg, lp, x, pat, layer_idx):
+    """The FFN sub-layer and its pre-norm, under the ``ffn`` scope."""
+    with jax.named_scope("ffn"):
+        h = L.rms_norm(lp["norm2"], x, cfg.norm_eps)
+        return L.ffn_block(lp["ffn"], h, _ffn_pat(cfg, pat), layer=layer_idx)
+
+
 def _run_dense(cfg, lp, x, pat, layer_idx, window):
-    h = L.rms_norm(lp["norm1"], x, cfg.norm_eps)
-    if cfg.mla:
-        a = L.mla_block(lp["attn"], h, n_heads=cfg.n_heads, qk_nope=cfg.qk_nope,
-                        qk_rope=cfg.qk_rope, v_dim=cfg.v_head_dim,
-                        rope_theta=cfg.rope_theta, chunk=cfg.attn_chunk)
-    else:
-        a = L.attention_block(lp["attn"], h, n_heads=cfg.n_heads,
-                              n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
-                              rope_theta=cfg.rope_theta, window=window,
-                              chunk=cfg.attn_chunk,
-                              pat=_attn_pat(cfg, pat), layer=layer_idx)
-    x = x + a
-    h = L.rms_norm(lp["norm2"], x, cfg.norm_eps)
-    f = L.ffn_block(lp["ffn"], h, _ffn_pat(cfg, pat), layer=layer_idx)
-    return x + f, jnp.zeros((), jnp.float32)
+    x = x + _attn(cfg, lp, x, pat, layer_idx, window)
+    return x + _ffn(cfg, lp, x, pat, layer_idx), jnp.zeros((), jnp.float32)
 
 
 def _run_moe(cfg, lp, x, pat, layer_idx, window):
-    h = L.rms_norm(lp["norm1"], x, cfg.norm_eps)
-    if cfg.mla:
-        a = L.mla_block(lp["attn"], h, n_heads=cfg.n_heads, qk_nope=cfg.qk_nope,
-                        qk_rope=cfg.qk_rope, v_dim=cfg.v_head_dim,
-                        rope_theta=cfg.rope_theta, chunk=cfg.attn_chunk)
-    else:
-        a = L.attention_block(lp["attn"], h, n_heads=cfg.n_heads,
-                              n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
-                              rope_theta=cfg.rope_theta, window=window,
-                              chunk=cfg.attn_chunk,
-                              pat=_attn_pat(cfg, pat), layer=layer_idx)
-    x = x + a
+    x = x + _attn(cfg, lp, x, pat, layer_idx, window)
     h = L.rms_norm(lp["norm2"], x, cfg.norm_eps)
     if cfg.moe_impl == "ep_shardmap":
         f, aux = L.moe_block_ep(lp["moe"], h, top_k=cfg.top_k,
@@ -324,17 +326,17 @@ def _run_ssm(cfg, lp, x, pat, layer_idx):
 
 
 def _run_shared_attn(cfg, sp, x, x0, pat, layer_idx):
-    h2 = jnp.concatenate([x, x0], -1)
-    h2 = L.rms_norm(sp["norm1"], h2, cfg.norm_eps)
-    a = L.attention_block(sp["attn"], h2, n_heads=cfg.n_heads,
-                          n_kv=cfg.n_kv_heads, head_dim=2 * cfg.d_model // cfg.n_heads,
-                          rope_theta=cfg.rope_theta, window=None,
-                          chunk=cfg.attn_chunk,
-                          pat=_attn_pat(cfg, pat), layer=layer_idx)
+    with jax.named_scope("attn"):
+        h2 = jnp.concatenate([x, x0], -1)
+        h2 = L.rms_norm(sp["norm1"], h2, cfg.norm_eps)
+        a = L.attention_block(sp["attn"], h2, n_heads=cfg.n_heads,
+                              n_kv=cfg.n_kv_heads,
+                              head_dim=2 * cfg.d_model // cfg.n_heads,
+                              rope_theta=cfg.rope_theta, window=None,
+                              chunk=cfg.attn_chunk,
+                              pat=_attn_pat(cfg, pat), layer=layer_idx)
     x = x + a
-    h = L.rms_norm(sp["norm2"], x, cfg.norm_eps)
-    f = L.ffn_block(sp["ffn"], h, _ffn_pat(cfg, pat), layer=layer_idx)
-    return x + f
+    return x + _ffn(cfg, sp, x, pat, layer_idx)
 
 
 def _ffn_pat(cfg, pat) -> plan_mod.BoundPlan:
@@ -408,14 +410,16 @@ def forward(cfg: ModelConfig, params, tokens, pat=NO_PATTERN,
     ``pat``: a core.plan.BoundPlan (or the legacy PatternArgs shim).
     Returns (logits[f32], aux_loss)."""
     pat = plan_mod.as_bound(pat)
-    if cfg.n_codebooks:
-        B, K, S = tokens.shape
-        x = jnp.zeros((B, S, cfg.d_model), cfg.jdtype)
-        for c in range(K):
-            x = x + jnp.take(params["embed"]["tok"][c], tokens[:, c], axis=0)
-    else:
-        B, S = tokens.shape
-        x = L.embed_tokens(params["embed"], tokens)
+    with jax.named_scope("embed"):
+        if cfg.n_codebooks:
+            B, K, S = tokens.shape
+            x = jnp.zeros((B, S, cfg.d_model), cfg.jdtype)
+            for c in range(K):
+                x = x + jnp.take(params["embed"]["tok"][c], tokens[:, c],
+                                 axis=0)
+        else:
+            B, S = tokens.shape
+            x = L.embed_tokens(params["embed"], tokens)
     if cfg.vision_tokens and vision_embeds is not None:
         vp = params["vision_proj"]
         v = L.rms_norm(vp["norm"], vision_embeds, cfg.norm_eps)
@@ -462,13 +466,15 @@ def forward(cfg: ModelConfig, params, tokens, pat=NO_PATTERN,
         (x, aux_total), _ = jax.lax.scan(body_fn, (x, aux_total), xs)
         layer_idx += count
 
-    x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
-    if cfg.n_codebooks:
-        logits = jnp.einsum("bsd,kdv->bksv", x, params["heads"]).astype(jnp.float32)
-    else:
-        logits = L.unembed(params["embed"], x)
-    if cfg.logit_softcap > 0:
-        logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
+    with jax.named_scope("head"):
+        x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
+        if cfg.n_codebooks:
+            logits = jnp.einsum("bsd,kdv->bksv", x,
+                                params["heads"]).astype(jnp.float32)
+        else:
+            logits = L.unembed(params["embed"], x)
+        if cfg.logit_softcap > 0:
+            logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
     return logits, aux_total
 
 
@@ -486,11 +492,12 @@ def lm_loss(cfg: ModelConfig, params, batch, pat=NO_PATTERN):
         pad = jnp.full(labels.shape[:-1] + (cfg.vision_tokens,), -1,
                        labels.dtype)
         labels = jnp.concatenate([pad, labels], -1)
-    mask = (labels >= 0).astype(jnp.float32)
-    safe = jnp.maximum(labels, 0)
-    logp = jax.nn.log_softmax(logits, -1)
-    nll = -jnp.take_along_axis(logp, safe[..., None], -1)[..., 0]
-    loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    with jax.named_scope("head"):
+        mask = (labels >= 0).astype(jnp.float32)
+        safe = jnp.maximum(labels, 0)
+        logp = jax.nn.log_softmax(logits, -1)
+        nll = -jnp.take_along_axis(logp, safe[..., None], -1)[..., 0]
+        loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
     total = loss + 0.01 * aux
     if cfg.mtp:
         total = total + 0.3 * _mtp_loss(cfg, params, batch, pat)

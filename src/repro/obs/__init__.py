@@ -6,7 +6,8 @@ Four pieces, composable but independently usable:
   one injectable-clock registry, labeled by pattern bucket
   ``(dp, bias, family, backend)``, with JSONL and Prometheus-text exporters.
 * ``trace``     — ``SpanTracer``: Chrome-trace/Perfetto-compatible JSONL
-  span events with near-zero overhead when disabled.
+  span events, each also a profiler annotation on the device trace's
+  clock; recording nothing when disabled.
 * ``recompile`` — ``RecompileWatchdog``: asserts the compiled-executable
   universe stays exactly ``plan.buckets()`` and surfaces unexpected
   compiles as a counter + warning instead of a silent multi-second stall.
@@ -30,8 +31,8 @@ from typing import Optional as _Optional
 class Observability:
     """One bundle of the four obs pieces, shared by train + serve.
 
-    Construct with ``trace_path`` to enable span tracing (disabled spans
-    cost one attribute load + one ``if``).  ``registry`` and ``watchdog``
+    Construct with ``trace_path`` to record spans as JSONL (disabled, a
+    span is a bare profiler annotation, about half a microsecond).  ``registry`` and ``watchdog``
     are always on — their hot-path cost is a dict lookup + float add.
     """
 

@@ -9,10 +9,17 @@ written traces from crashed runs still load, and one-event-per-line keeps
 the file greppable / schema-checkable line-by-line
 (``tools/validate_obs.py``).
 
-Overhead discipline: a *disabled* tracer's ``span()`` returns one shared
-no-op context manager — no timestamping, no allocation per call beyond the
-method dispatch — so instrumented hot loops pay effectively nothing when
-tracing is off (the default everywhere).
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name, so
+the program's spans land on the host plane of a profiler trace
+(``.xplane.pb``), on the clock of the device's operations: a reduction of
+that trace can name the span the host was in while the device sat idle.
+Only the name goes to the annotation; ``args`` stay in the JSONL events.
+
+Overhead discipline: the annotation is always opened, enabled or not.
+Outside a profiler session it is one native TraceMe that records nothing
+(about half a microsecond per span on a CPU host).  A *disabled* tracer
+records no JSONL event: no timestamp, no dict, nothing kept — the default
+everywhere but ``launch/train.py --trace``.
 """
 from __future__ import annotations
 
@@ -21,38 +28,29 @@ import os
 import time
 from typing import Optional
 
-
-class _NullSpan:
-    """Shared no-op context manager for disabled tracers."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+from jax.profiler import TraceAnnotation
 
 
 class _Span:
-    """One live span: records a complete event on ``__exit__``."""
+    """One live span: a profiler annotation, and a complete event recorded
+    on ``__exit__``."""
 
-    __slots__ = ("tracer", "name", "args", "t0")
+    __slots__ = ("tracer", "name", "args", "t0", "annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: dict):
         self.tracer = tracer
         self.name = name
         self.args = args
+        self.annotation = TraceAnnotation(name)
 
     def __enter__(self):
+        self.annotation.__enter__()
         self.t0 = self.tracer._now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = self.tracer._now_us()
+        self.annotation.__exit__(*exc)
         self.tracer._events.append({
             "name": self.name,
             "ph": "X",
@@ -91,13 +89,15 @@ class SpanTracer:
 
     # ---- recording ---------------------------------------------------------
     def span(self, name: str, **args):
-        """Context manager timing a block as one complete trace event.
+        """Context manager timing a block as one complete trace event, and
+        a profiler annotation named ``name`` around it.
 
         Keyword args land in the event's ``args`` dict (Perfetto shows
         them in the span detail pane) — e.g. ``span("step", dp=2, bias=1)``.
+        A disabled tracer returns the bare annotation and records nothing.
         """
         if not self.enabled:
-            return _NULL_SPAN
+            return TraceAnnotation(name)
         return _Span(self, name, args)
 
     def instant(self, name: str, **args) -> None:

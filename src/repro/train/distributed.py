@@ -379,42 +379,15 @@ class DistributedTrainer:
         watchdog is frozen: any further compile is a violation.  Nothing
         executes: ``lower().compile()`` fills the same cache the steps hit,
         and needs no second copy of the state on the device.
-
-        Each bucket's compiled module is also analyzed
-        (``launch/hlo_analysis`` + the ``ffn_pattern`` named-scope
-        attribution of ``launch/hlo_profile``) into per-bucket gauges —
-        ``ffn_pattern_dot_flops`` validates the paper's 1/dp FFN FLOP
-        claim live, on the module XLA actually built.
         """
         batch = jax.tree.map(jnp.asarray, batch_fn(0))
         tracer = self.obs.tracer
         with set_mesh_and_rules(self.mesh, self.rules):
             for dp, b in self.plan0.buckets():
                 fn = self._step_fn(dp, b, batch)
-                with tracer.span("compile", dp=dp, bias=b):
-                    compiled = fn.lower(self.state, batch,
-                                        jnp.float32(0.0)).compile()
-                self._gauge_compiled(dp, b, compiled)
+                with tracer.span("train.compile", dp=dp, bias=b):
+                    fn.lower(self.state, batch, jnp.float32(0.0)).compile()
         self.obs.watchdog.freeze()
-
-    def _gauge_compiled(self, dp: int, bias: int, compiled) -> None:
-        """Per-bucket FLOP/byte gauges from the compiled HLO module."""
-        from repro.launch.hlo_analysis import analyze_hlo
-        from repro.launch.hlo_profile import scoped_dot_flops
-        try:
-            hlo = compiled.as_text()
-        except Exception:   # backend without HLO text dumps
-            return
-        labels = bucket_labels(dp, bias, family=self.plan.family,
-                               backend=self.plan.backend)
-        analysis = analyze_hlo(hlo)
-        reg = self.obs.registry
-        reg.gauge("module_dot_flops", labels).set(analysis["dot_flops"])
-        reg.gauge("module_dot_bytes", labels).set(analysis["dot_bytes"])
-        reg.gauge("module_collective_bytes", labels).set(
-            analysis["collective_bytes"])
-        reg.gauge("ffn_pattern_dot_flops", labels).set(
-            scoped_dot_flops(hlo, "ffn_pattern"))
 
     # ---- fault tolerance ---------------------------------------------------
     def maybe_resume(self):
@@ -475,7 +448,7 @@ class DistributedTrainer:
             if self.obs.drift is not None:
                 drift_rep = self.obs.drift.report(
                     min_samples=min(50, ctl.cfg.resync_every))
-            with tracer.span("search_resync", step=step):
+            with tracer.span("train.search_resync", step=step):
                 new_plan = ctl.resync(step)
             if drift_rep is not None:
                 ctl.resync_log[-1]["drift_verdict"] = drift_rep["verdict"]
@@ -486,53 +459,64 @@ class DistributedTrainer:
     # ---- the loop ----------------------------------------------------------
     def run(self, batch_fn: Callable[[int], dict],
             until: Optional[int] = None) -> list[dict]:
-        """Train until ``until`` (default tcfg.steps); returns history."""
+        """Train until ``until`` (default tcfg.steps); returns history.
+
+        Each step runs under the ``train.*`` phase spans, in this order:
+        ``sample``, ``data``, ``lr``, ``dispatch`` (the jitted call, with a
+        new bucket's first compile), ``sync`` (waiting for the loss and
+        reading it back) and ``record``; ``train.run`` covers the call."""
         until = until or self.tcfg.steps
-        self.maybe_resume()
         tracer, reg = self.obs.tracer, self.obs.registry
-        with set_mesh_and_rules(self.mesh, self.rules):
-            for step in range(self.start_step, until):
-                bound = self.plan.sample(step)
-                if (bound.dp, bound.bias) not in self._superset:
-                    # defense in depth: with_dist already forbids support
-                    # escapes, so an off-superset draw means state
-                    # corruption — raise rather than compile on the hot path
-                    raise BucketSupersetViolation(
-                        f"sampled bucket (dp={bound.dp}, bias={bound.bias})"
-                        f" outside the frozen superset "
-                        f"{sorted(self._superset)}")
-                if self.obs.drift is not None:
-                    self.obs.drift.observe_bound(bound)
-                with tracer.span("data", step=step):
-                    batch = jax.tree.map(jnp.asarray, batch_fn(step))
-                with tracer.span("dispatch", dp=bound.dp, bias=bound.bias):
-                    fn = self._step_fn(bound.dp, bound.bias, batch)
-                t0 = time.perf_counter()
-                with tracer.span("train_step", step=step, dp=bound.dp,
-                                 bias=bound.bias):
-                    self.state, metrics = fn(self.state, batch,
-                                             jnp.float32(self.lr_fn(step)))
-                    jax.block_until_ready(metrics["loss"])
-                dt = time.perf_counter() - t0
-                slow = self.watchdog.observe(dt)
-                blabels = bucket_labels(bound.dp, bound.bias)
-                reg.histogram("train_step_time_s", blabels).record(dt)
-                reg.counter("train_steps_total", blabels).inc()
-                if slow:
-                    reg.counter("train_stragglers_total", blabels).inc()
-                    tracer.instant("straggler", step=step, dt=dt)
-                rec = {"step": step, "loss": float(metrics["loss"]),
-                       "dp": bound.dp, "bias": bound.bias, "dt": dt,
-                       "straggler": slow}
-                self.history.append(rec)
-                if self.online_search is not None:
-                    self._search_hook(step, rec, tracer)
-                if step % self.tcfg.log_every == 0:
-                    print(f"step {step}: loss={rec['loss']:.4f} "
-                          f"dp={bound.dp} dt={dt*1e3:.0f}ms"
-                          + (" [STRAGGLER]" if slow else ""), flush=True)
-                self._maybe_checkpoint(step)
-        self.async_ckpt.wait()
-        if self.tcfg.ckpt_dir:
-            ckpt_lib.save(self.tcfg.ckpt_dir, until - 1, self.state)
+        with tracer.span("train.run", start=self.start_step, until=until):
+            self.maybe_resume()
+            with set_mesh_and_rules(self.mesh, self.rules):
+                for step in range(self.start_step, until):
+                    self._run_step(step, batch_fn, tracer, reg)
+            self.async_ckpt.wait()
+            if self.tcfg.ckpt_dir:
+                ckpt_lib.save(self.tcfg.ckpt_dir, until - 1, self.state)
         return self.history
+
+    def _run_step(self, step: int, batch_fn, tracer, reg) -> None:
+        with tracer.span("train.sample", step=step):
+            bound = self.plan.sample(step)
+            if (bound.dp, bound.bias) not in self._superset:
+                # defense in depth: with_dist already forbids support
+                # escapes, so an off-superset draw means state
+                # corruption — raise rather than compile on the hot path
+                raise BucketSupersetViolation(
+                    f"sampled bucket (dp={bound.dp}, bias={bound.bias})"
+                    f" outside the frozen superset "
+                    f"{sorted(self._superset)}")
+            if self.obs.drift is not None:
+                self.obs.drift.observe_bound(bound)
+        with tracer.span("train.data", step=step):
+            batch = jax.tree.map(jnp.asarray, batch_fn(step))
+        t0 = time.perf_counter()
+        with tracer.span("train.lr", step=step):
+            lr = jnp.float32(self.lr_fn(step))
+        with tracer.span("train.dispatch", dp=bound.dp, bias=bound.bias):
+            fn = self._step_fn(bound.dp, bound.bias, batch)
+            self.state, metrics = fn(self.state, batch, lr)
+        with tracer.span("train.sync", step=step):
+            jax.block_until_ready(metrics["loss"])
+            dt = time.perf_counter() - t0
+            loss = float(metrics["loss"])
+        with tracer.span("train.record", step=step):
+            slow = self.watchdog.observe(dt)
+            blabels = bucket_labels(bound.dp, bound.bias)
+            reg.histogram("train_step_time_s", blabels).record(dt)
+            reg.counter("train_steps_total", blabels).inc()
+            if slow:
+                reg.counter("train_stragglers_total", blabels).inc()
+                tracer.instant("straggler", step=step, dt=dt)
+            rec = {"step": step, "loss": loss, "dp": bound.dp,
+                   "bias": bound.bias, "dt": dt, "straggler": slow}
+            self.history.append(rec)
+            if self.online_search is not None:
+                self._search_hook(step, rec, tracer)
+            if step % self.tcfg.log_every == 0:
+                print(f"step {step}: loss={rec['loss']:.4f} "
+                      f"dp={bound.dp} dt={dt*1e3:.0f}ms"
+                      + (" [STRAGGLER]" if slow else ""), flush=True)
+            self._maybe_checkpoint(step)

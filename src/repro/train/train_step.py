@@ -85,8 +85,10 @@ def make_train_step(cfg: ModelConfig, optimizer, *, microbatches: int = 1,
 
         if compress_grads:
             grads = terngrad_compress_decompress(grads)
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        params, opt_state = optimizer.update(params, grads, opt_state, lr)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, clip_norm)
+            params, opt_state = optimizer.update(params, grads, opt_state,
+                                                 lr)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
     return train_step

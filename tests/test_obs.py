@@ -1,8 +1,9 @@
 """Unified observability layer: registry/tracer/watchdog/drift units, the
-registry-generic no-recompile sweep, and the CPU smoke acceptance — a short
-instrumented train run must emit a Perfetto-loadable trace, per-bucket FFN
-FLOP gauges at 1/dp of dense, zero recompile violations after warm_start,
-and an in-distribution drift verdict for the plan's own draws."""
+registry-generic no-recompile sweep, the tracer's spans in a profiler
+trace, and the CPU smoke acceptance — a short instrumented train run must
+emit a Perfetto-loadable trace, FFN FLOPs at 1/dp of dense in each compiled
+bucket, zero recompile violations after warm_start, and an in-distribution
+drift verdict for the plan's own draws."""
 import functools
 import json
 
@@ -15,7 +16,6 @@ from repro.core.plan import BACKENDS, FAMILIES, DropoutPlan, get_family
 from repro.obs import (DriftMonitor, MetricsRegistry, Observability,
                        RecompileWatchdog, SpanTracer, bucket_labels)
 from repro.obs.recompile import RecompileViolation
-from repro.obs.trace import _NULL_SPAN
 
 from tools.validate_obs import validate_metrics, validate_trace
 
@@ -113,12 +113,62 @@ def test_serve_histogram_is_registry_histogram_with_cap():
 # --------------------------------------------------------------------------
 
 def test_tracer_disabled_is_shared_noop():
+    """A disabled tracer records no JSONL event; its spans are bare
+    profiler annotations, which record nothing outside a profiler
+    session."""
     t = SpanTracer(enabled=False)
-    assert t.span("x", a=1) is _NULL_SPAN is t.span("y")
+    with t.span("x", a=1) as span:
+        pass
+    assert isinstance(span, jax.profiler.TraceAnnotation)
     t.instant("i")
     t.counter("c", v=1)
     assert t.events() == []
     assert t.write("/nonexistent/never_written") is None
+
+
+def _profiled_host_events(trace_dir, prefix: str) -> list:
+    """(name, start ns, duration ns) of the host events in the newest
+    profiler trace under ``trace_dir`` whose names start with ``prefix``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            out += [(e.name, e.start_ns, e.duration_ns) for e in line.events
+                    if e.name.startswith(prefix)]
+    return sorted(out, key=lambda e: e[1])
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["jsonl", "off"])
+def test_span_is_a_host_event_of_the_profiler_trace(tmp_path, enabled):
+    """Every span, recorded to JSONL or not, is a host event of the same
+    name in a ``jax.profiler`` trace, on the clock of the device's
+    operations; the span's args stay out of the event's name, and the
+    JSONL it writes still validates."""
+    t = SpanTracer(path=str(tmp_path / "trace.jsonl"), enabled=enabled)
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        with t.span("train.outer", step=7):
+            with t.span("train.inner", dp=2, bias=1):
+                jax.block_until_ready(jnp.ones(4) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    (outer, t0, dur), (inner, t1, dur1) = _profiled_host_events(
+        tmp_path / "prof", "train.")
+    assert (outer, inner) == ("train.outer", "train.inner")
+    assert t0 <= t1 and t1 + dur1 <= t0 + dur
+    if enabled:
+        assert t.write() is not None
+        assert validate_trace(str(tmp_path / "trace.jsonl")) == 2
+        assert [e["name"] for e in t.events()] == ["train.inner",
+                                                   "train.outer"]
+    else:
+        assert t.events() == [] and t.write() is None
 
 
 def test_tracer_trace_is_perfetto_loadable_and_schema_valid(tmp_path):
@@ -418,14 +468,16 @@ def test_instrumented_train_smoke_acceptance(tmp_path):
     tr.run(data.batch)
     obs.watchdog.assert_clean()              # ... and through the run
 
-    # (b) per-bucket FFN FLOP gauges = 1/dp of dense, from the real HLO
-    gauges = {dict(m.labels)["dp"]: m.value
-              for m in obs.registry.metrics()
-              if m.name == "ffn_pattern_dot_flops"
-              and dict(m.labels)["bias"] == "0"}
-    dense = gauges["1"]
-    assert dense > 0
-    assert gauges["2"] / dense == pytest.approx(0.5, abs=0.02)
+    # (b) FFN FLOPs = 1/dp of dense, in the HLO of each compiled bucket
+    from repro.launch.hlo_profile import scoped_dot_flops
+    from repro.parallel.sharding import set_mesh_and_rules
+    batch = jax.tree.map(jnp.asarray, data.batch(0))
+    with set_mesh_and_rules(tr.mesh, tr.rules):
+        flops = {dp: scoped_dot_flops(
+            tr._step_fn(dp, 0, batch).lower(tr.state, batch, jnp.float32(0))
+            .compile().as_text(), "ffn_pattern") for dp in (1, 2)}
+    assert flops[1] > 0
+    assert flops[2] / flops[1] == pytest.approx(0.5, abs=0.02)
 
     # (d) drift verdict for the plan's own draws
     drift = obs.drift.report(min_samples=30)
@@ -437,8 +489,9 @@ def test_instrumented_train_smoke_acceptance(tmp_path):
     evs = json.loads(open(trace_path).read().rstrip().rstrip(",") + "]")
     assert len(evs) == n
     names = {e["name"] for e in evs}
-    assert {"compile", "data", "dispatch", "train_step"} <= names
-    steps = [e for e in evs if e["name"] == "train_step"]
+    assert {"train.compile", "train.data", "train.dispatch",
+            "train.sync"} <= names
+    steps = [e for e in evs if e["name"] == "train.dispatch"]
     assert len(steps) == 30
     assert all(e["args"]["dp"] in (1, 2) for e in steps)
 
@@ -446,3 +499,38 @@ def test_instrumented_train_smoke_acceptance(tmp_path):
     hists = [m for m in obs.registry.metrics()
              if m.name == "train_step_time_s"]
     assert sum(m.count for m in hists) == 30
+
+
+PHASES = ["train.sample", "train.data", "train.lr", "train.dispatch",
+          "train.sync", "train.record"]
+
+
+def test_trainer_emits_each_phase_span_once_per_step_in_order(tmp_path):
+    """``DistributedTrainer.run`` wraps each step in the six phase spans,
+    once each and in order, all inside one ``train.run`` span per call."""
+    from repro.configs import get_smoke
+    from repro.core.plan import identity_plan
+    from repro.data.pipeline import SyntheticLMData
+    from repro.models import init_lm, materialize
+    from repro.optim.optimizers import AdamW
+    from repro.train.distributed import DistributedTrainer, TrainerConfig
+
+    cfg = get_smoke("qwen2_1_5b")
+    params = materialize(jax.random.PRNGKey(0), init_lm(cfg)[0])
+    obs = Observability.create(trace_path=str(tmp_path / "trace.jsonl"))
+    data = SyntheticLMData(vocab=cfg.vocab, seq_len=16, global_batch=2)
+    tr = DistributedTrainer(cfg, AdamW(), params, obs=obs,
+                            plan=identity_plan(nb=cfg.pattern_nb),
+                            tcfg=TrainerConfig(steps=3, log_every=1000))
+    tr.run(data.batch)
+    evs = sorted(obs.tracer.events(), key=lambda e: (e["ts"], -e["dur"]))
+    spans = [e for e in evs if e["ph"] == "X"]
+    assert spans[0]["name"] == "train.run"
+    run_end = spans[0]["ts"] + spans[0]["dur"]
+    assert [e["name"] for e in spans[1:]] == PHASES * 3
+    assert all(e["ts"] + e["dur"] <= run_end for e in spans)
+    # phases do not overlap: each ends before the next begins
+    for a, b in zip(spans[1:], spans[2:]):
+        assert a["ts"] + a["dur"] <= b["ts"]
+    assert [e["args"]["step"] for e in spans[1:]
+            if e["name"] == "train.sample"] == [0, 1, 2]

@@ -147,3 +147,63 @@ def test_misaligned_block_raises_clear_error(one_chip, no_persistent_cache,
     }
     with pytest.raises(ValueError, match=rf"block of {block} over d_ff={N}"):
         calls[entry]()
+
+
+def test_step_names_are_stable(one_chip, no_persistent_cache, monkeypatch):
+    """What a device trace reads the training step by: its executable is
+    ``jit_step``, each compact kernel's instruction carries a name of
+    ``PATTERN_KERNELS`` (the explicit ``pallas_call`` names), and the five
+    named scopes reach the ``op_name`` metadata, forward and backward.
+    One rdp bucket of a small model with lane-wide pattern blocks."""
+    import dataclasses
+    import re
+    import sys
+    from pathlib import Path
+
+    from repro.configs import get_smoke
+    from repro.core.plan import DropoutPlan
+    from repro.data.pipeline import SyntheticLMData
+    from repro.models import init_lm, materialize
+    from repro.optim.optimizers import AdamW
+    from repro.train.distributed import DistributedTrainer, TrainerConfig
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]
+                           / "benchmarks" / "chip"))
+    from kernels import PATTERN_KERNELS, base_name
+    from trace_layers import SCOPES, scope_of
+
+    cfg = dataclasses.replace(get_smoke("qwen2_1_5b"), d_ff=512,
+                              pattern_nb=4, dtype="bfloat16")
+    params = materialize(jax.random.PRNGKey(0), init_lm(cfg)[0])
+    plan = DropoutPlan(family="rdp", dist=(0.5, 0.5), nb=cfg.pattern_nb,
+                       block=cfg.d_ff // cfg.pattern_nb, backend="pallas")
+    tr = DistributedTrainer(cfg, AdamW(), params, plan=plan,
+                            tcfg=TrainerConfig(steps=1))
+    # the accumulation constraint names the CPU mesh's devices; the
+    # described chip's one-device step has nothing to constrain
+    tr._acc_sh = None
+    batch = jax.tree.map(jnp.asarray, SyntheticLMData(
+        vocab=cfg.vocab, seq_len=128, global_batch=1).batch(0))
+    fn = tr._step_fn(2, 1, batch)
+    assert fn.lower(tr.state, batch, jnp.float32(0)).as_text().startswith(
+        "module @jit_step")
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    hlo = jax.jit(fn.__wrapped__).lower(
+        jax.tree.map(sds, tr.state), jax.tree.map(sds, batch),
+        sds(jnp.float32(0))).compile().as_text()
+    assert hlo.startswith("HloModule jit_step")
+    kernels = {base_name(m) for m in re.findall(
+        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)}
+    assert kernels == {"rdp_matmul_cols", "rdp_matmul_rows",
+                       "rdp_cols_dgrad", "rdp_cols_wgrad",
+                       "rdp_rows_dgrad", "rdp_rows_wgrad"}
+    assert kernels <= PATTERN_KERNELS
+    paths = re.findall(r'op_name="([^"]*)"', hlo)
+    assert {scope_of(p) for p in paths} == set(SCOPES) | {""}
+    # the backward pass keeps the scopes too
+    backward = {scope_of(p) for p in paths if "transpose(" in p}
+    assert {"embed", "attn", "ffn", "head"} <= backward
