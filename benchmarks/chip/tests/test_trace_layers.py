@@ -87,6 +87,38 @@ def test_op_names_come_from_the_hlo_in_the_trace(tmp_path):
     assert TL.hlo_op_names(path, modules=set()) == {}
 
 
+def test_events_add_the_programs_spans_from_run_pys_trace(tmp_path,
+                                                          monkeypatch):
+    """A reader's events are those ``run.py`` reduced, copied, with the
+    program's spans added from the trace still in ``run.py``'s
+    ``TRACE_DIR``, read once for all readers; with no trace there, the
+    events are left as they were."""
+    import jax
+    import jax.numpy as jnp
+    from repro.obs import SpanTracer
+
+    monkeypatch.setattr(R, "TRACE_DIR", tmp_path)
+    tracer = SpanTracer(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation(TR.WINDOW):
+            with tracer.span("train.sync"):
+                jax.block_until_ready(jnp.ones(4) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    given = TR.extract(str(tmp_path))
+    before = [dict(e) for e in given]
+    ev = TL.events({"events": given})
+    assert given == before
+    (sync,) = [e for e in ev if e["kind"] == "span"]
+    t0, t1 = TR.window(ev)
+    assert sync["name"] == "train.sync"
+    assert t0 <= sync["t0"] and sync["t0"] + sync["dur"] <= t1
+    assert TL.events({"events": given}) is ev
+    monkeypatch.setattr(R, "TRACE_DIR", tmp_path / "removed")
+    assert TL.events({"events": given}) is given
+
+
 def test_scopes_are_put_by_the_executable_around_each_op():
     ev = [{"kind": "module", "plane": DEV, "name": "jit_step(1)", "t0": 0,
            "dur": 100},

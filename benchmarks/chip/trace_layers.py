@@ -18,28 +18,25 @@ same trace, on the same clock:
   event gains ``scope``: the first of ``SCOPES`` on the ``op_name`` of
   its instruction, in the executable that ran around it, or "".
 
-``extract`` gives ``trace_reduce.extract``'s events with these added, so
-every function of ``trace_reduce`` reads them too; ``scope_seconds`` and
-``idle_by_span`` are pure functions of such a list.  A trace of a program
-that writes neither (an older commit) gives no span and no scope, and the
-readers built on them return None.
+``with_program`` adds both to the events ``trace_reduce.extract`` gave,
+so every function of ``trace_reduce`` reads the result too;
+``scope_seconds`` and ``idle_by_span`` are pure functions of such a list.
+A trace of a program that writes neither (an older commit) gives no span
+and no scope, and the readers built on them return None.
 """
 from __future__ import annotations
 
 import bisect
 import collections
-import functools
 import glob
 import os
-from pathlib import Path
 
+import run as R
 import trace_reduce as TR
 
 SPAN_PREFIX = "train."
 SCOPES = ("embed", "attn", "ffn", "head", "optimizer")
 OUTSIDE = "outside"         # idle time no program span covers: the harness
-# where run.py has the profiler write (its TRACE_DIR), until it removes it
-TRACE_DIR = Path(__file__).resolve().parents[2] / ".bench_trace"
 METADATA_PLANE = "/host:metadata"
 HLO_STAT = "Hlo Proto"
 
@@ -157,49 +154,24 @@ def hlo_op_names(path, modules=None) -> dict:
     return {}
 
 
-def extract(trace_dir) -> list[dict]:
-    """Events of the newest ``.xplane.pb`` under ``trace_dir``: those of
-    ``trace_reduce.extract``, the program's spans, and each operation's
-    scope."""
+def with_program(events: list[dict], path) -> list[dict]:
+    """``events`` (from ``trace_reduce.extract`` of the ``.xplane.pb`` at
+    ``path``) with the program's spans added and each operation's scope
+    set; the given events are copied, not changed."""
     from jax.profiler import ProfileData
-    paths = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
-                      recursive=True)
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    path = max(paths, key=os.path.getmtime)
-    data = ProfileData.from_file(path)
-    events = []
-    for plane in data.planes:
-        device = plane.name.startswith("/device:")
+    out = [dict(e, scope="") for e in events]
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/device:"):
+            continue
         for line in plane.lines:
-            kind = TR.DEVICE_LINES.get(line.name) if device else "host"
-            if kind is None:
-                continue
-            for ev in line.events:
-                name = ev.name
-                if kind == "host":
-                    if name.startswith(SPAN_PREFIX):
-                        ev_kind = "span"
-                    elif name.startswith(TR.ANNOTATION_PREFIX):
-                        ev_kind = "host"
-                    else:
-                        continue
-                else:
-                    ev_kind = kind
-                stats = dict(ev.stats) if kind == "op" else {}
-                long = str(stats.get("long_name", "") or name)
-                target = TR._TARGET.search(long)
-                if kind == "op":
-                    name = name.split(" = ")[0].lstrip("%")
-                events.append({
-                    "kind": ev_kind, "plane": plane.name, "name": name,
-                    "t0": float(ev.start_ns), "dur": float(ev.duration_ns),
-                    "long": long[:TR.LONG_CHARS],
-                    "target": target.group(1) if target else "",
-                    "scope": ""})
-    modules = {e["name"] for e in events if e["kind"] == "module"}
-    _put_scopes(events, hlo_op_names(path, modules))
-    return events
+            out += [{"kind": "span", "plane": plane.name, "name": ev.name,
+                     "t0": float(ev.start_ns), "dur": float(ev.duration_ns),
+                     "long": ev.name, "target": "", "scope": ""}
+                    for ev in line.events
+                    if ev.name.startswith(SPAN_PREFIX)]
+    modules = {e["name"] for e in out if e["kind"] == "module"}
+    _put_scopes(out, hlo_op_names(path, modules))
+    return out
 
 
 def _put_scopes(events: list, op_names: dict) -> None:
@@ -223,27 +195,29 @@ def _put_scopes(events: list, op_names: dict) -> None:
             e["scope"] = scope_of(op_names.get(module, {}).get(e["name"], ""))
 
 
-@functools.lru_cache(maxsize=1)
-def _extract_cached(path: str, mtime: float) -> tuple:
-    return tuple(extract(os.path.dirname(path)))
+_LAST: dict = {}          # the one reading the readers of a run share
 
 
 def events(ctx: dict) -> list:
     """The traced window's events with spans and scopes, for a reader.
 
     ``ctx["events"]`` where they carry them already (a recorded slice in
-    the tests); else the trace ``run.py`` has just reduced, read again
-    from its directory (each reader of a run shares one reading); else
+    the tests); else those events with what ``with_program`` reads from
+    the trace ``run.py`` has just reduced, while its ``TRACE_DIR`` still
+    holds it (read once for all the readers of a run); else
     ``ctx["events"]`` as they are."""
     given = ctx["events"]
     if any(e["kind"] == "span" or "scope" in e for e in given):
         return given
-    paths = glob.glob(os.path.join(str(TRACE_DIR), "**", "*.xplane.pb"),
+    paths = glob.glob(os.path.join(str(R.TRACE_DIR), "**", "*.xplane.pb"),
                       recursive=True)
     if not paths:
         return given
-    newest = max(paths, key=os.path.getmtime)
-    return list(_extract_cached(newest, os.path.getmtime(newest)))
+    path = max(paths, key=os.path.getmtime)
+    key = (path, os.path.getmtime(path))
+    if _LAST.get("key") != key or _LAST.get("given") is not given:
+        _LAST.update(key=key, given=given, out=with_program(given, path))
+    return _LAST["out"]
 
 
 # --------------------------------------------------------------------------

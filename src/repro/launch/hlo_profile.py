@@ -100,7 +100,7 @@ def scoped_dot_flops(hlo: str, scope: str, default_group: int = 1) -> float:
     Sums every dot whose ``op_name`` metadata contains ``scope`` — e.g.
     ``scope="ffn_pattern"`` isolates the pattern-compacted FFN matmuls
     (``models/layers.py`` wraps ``ffn_block`` in that scope), which is how
-    the trainer's ``warm_start()`` gauges the 1/dp FLOP claim per bucket.
+    the tests check the 1/dp FLOP claim on each compiled bucket.
     """
     totals = attribute(hlo, default_group=default_group)
     return sum(v for (kind, _, opname), v in totals.items()

@@ -304,8 +304,9 @@ class DistributedTrainer:
                                 step=jnp.zeros((), jnp.int32),
                                 extras=extras)
         # f32 grad-accumulation buffers share the ZeRO-1 layout (the
-        # acc_shardings hook of make_train_step)
-        self._acc_sh = jax.tree.map(
+        # acc_shardings hook of make_train_step); one device has nothing
+        # to shard them over, and its step then binds no mesh's devices
+        self._acc_sh = None if self.mesh.size == 1 else jax.tree.map(
             lambda sh, p: zero1_opt_sharding(sh, p.shape),
             self.state_sh.params, params)
 

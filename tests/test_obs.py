@@ -438,6 +438,17 @@ def test_bench_record_carries_provenance():
 # CPU smoke acceptance: instrumented trainer end to end
 # --------------------------------------------------------------------------
 
+def _compiled_ffn_flops(tr, batch, dp: int) -> float:
+    """Dot FLOPs under ``ffn_pattern`` in the compiled HLO of the trainer's
+    ``(dp, 0)`` bucket."""
+    from repro.launch.hlo_profile import scoped_dot_flops
+    from repro.parallel.sharding import set_mesh_and_rules
+    with set_mesh_and_rules(tr.mesh, tr.rules):
+        return scoped_dot_flops(
+            tr._step_fn(dp, 0, batch).lower(tr.state, batch, jnp.float32(0))
+            .compile().as_text(), "ffn_pattern")
+
+
 def test_instrumented_train_smoke_acceptance(tmp_path):
     """A short CPU train run with tracing on must satisfy all four
     acceptance properties of the observability layer at once."""
@@ -469,13 +480,8 @@ def test_instrumented_train_smoke_acceptance(tmp_path):
     obs.watchdog.assert_clean()              # ... and through the run
 
     # (b) FFN FLOPs = 1/dp of dense, in the HLO of each compiled bucket
-    from repro.launch.hlo_profile import scoped_dot_flops
-    from repro.parallel.sharding import set_mesh_and_rules
     batch = jax.tree.map(jnp.asarray, data.batch(0))
-    with set_mesh_and_rules(tr.mesh, tr.rules):
-        flops = {dp: scoped_dot_flops(
-            tr._step_fn(dp, 0, batch).lower(tr.state, batch, jnp.float32(0))
-            .compile().as_text(), "ffn_pattern") for dp in (1, 2)}
+    flops = {dp: _compiled_ffn_flops(tr, batch, dp) for dp in (1, 2)}
     assert flops[1] > 0
     assert flops[2] / flops[1] == pytest.approx(0.5, abs=0.02)
 
@@ -499,6 +505,42 @@ def test_instrumented_train_smoke_acceptance(tmp_path):
     hists = [m for m in obs.registry.metrics()
              if m.name == "train_step_time_s"]
     assert sum(m.count for m in hists) == 30
+
+
+@pytest.fixture(scope="module")
+def smoke_launch_trainer():
+    """The trainer of CI's Observability smoke (``launch.train --arch
+    qwen2-1.5b --smoke --batch 4 --seq 32 --dropout 0.5 --dp-max 4``),
+    one batch, and its dense bucket's FFN FLOPs."""
+    from repro.configs import get_smoke
+    from repro.core.plan import build_plan
+    from repro.data.pipeline import SyntheticLMData
+    from repro.models import init_lm, materialize
+    from repro.optim.optimizers import AdamW
+    from repro.train.distributed import DistributedTrainer, TrainerConfig
+
+    cfg = get_smoke("qwen2_1_5b")
+    params = materialize(jax.random.PRNGKey(0), init_lm(cfg)[0])
+    plan = build_plan("rdp", 0.5, nb=cfg.pattern_nb, dp_max=4,
+                      block=cfg.d_ff // cfg.pattern_nb)
+    tr = DistributedTrainer(cfg, AdamW(), params, plan=plan,
+                            tcfg=TrainerConfig(steps=1))
+    batch = jax.tree.map(jnp.asarray, SyntheticLMData(
+        vocab=cfg.vocab, seq_len=32, global_batch=4).batch(0))
+    return tr, batch, _compiled_ffn_flops(tr, batch, 1)
+
+
+@pytest.mark.parametrize("dp", [2, 4])
+def test_compiled_ffn_flops_are_one_over_dp_in_every_bucket(
+        smoke_launch_trainer, dp):
+    """Each patterned bias-0 bucket of the smoke plan (dp 2 and 4; 3 does
+    not divide its 8 blocks) compiles to 1/dp of the dense bucket's FFN
+    dot FLOPs."""
+    tr, batch, dense = smoke_launch_trainer
+    assert sorted(d for d, b in tr.plan0.buckets() if b == 0) == [1, 2, 4]
+    assert dense > 0
+    assert _compiled_ffn_flops(tr, batch, dp) / dense == pytest.approx(
+        1 / dp, abs=0.02)
 
 
 PHASES = ["train.sample", "train.data", "train.lr", "train.dispatch",

@@ -157,7 +157,6 @@ def test_step_names_are_stable(one_chip, no_persistent_cache, monkeypatch):
     One rdp bucket of a small model with lane-wide pattern blocks."""
     import dataclasses
     import re
-    import sys
     from pathlib import Path
 
     from repro.configs import get_smoke
@@ -166,8 +165,8 @@ def test_step_names_are_stable(one_chip, no_persistent_cache, monkeypatch):
     from repro.models import init_lm, materialize
     from repro.optim.optimizers import AdamW
     from repro.train.distributed import DistributedTrainer, TrainerConfig
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1]
-                           / "benchmarks" / "chip"))
+    monkeypatch.syspath_prepend(str(Path(__file__).resolve().parents[1]
+                                    / "benchmarks" / "chip"))
     from kernels import PATTERN_KERNELS, base_name
     from trace_layers import SCOPES, scope_of
 
@@ -178,9 +177,6 @@ def test_step_names_are_stable(one_chip, no_persistent_cache, monkeypatch):
                        block=cfg.d_ff // cfg.pattern_nb, backend="pallas")
     tr = DistributedTrainer(cfg, AdamW(), params, plan=plan,
                             tcfg=TrainerConfig(steps=1))
-    # the accumulation constraint names the CPU mesh's devices; the
-    # described chip's one-device step has nothing to constrain
-    tr._acc_sh = None
     batch = jax.tree.map(jnp.asarray, SyntheticLMData(
         vocab=cfg.vocab, seq_len=128, global_batch=1).batch(0))
     fn = tr._step_fn(2, 1, batch)
