@@ -27,7 +27,8 @@ import numpy as np
 
 from repro.core import plan as plan_mod
 from repro.core.plan import BoundPlan, _slice_blocks
-from repro.parallel.sharding import constrain
+from repro.kernels import attention as attn_kernel
+from repro.parallel.sharding import constrain, current_mesh
 
 Init = jax.nn.initializers
 
@@ -148,11 +149,45 @@ def init_attention(d_model: int, n_heads: int, n_kv: int, head_dim: int,
 def blockwise_attention(q, k, v, *, causal: bool = True,
                         window: Optional[int] = None, chunk: int = 1024,
                         q_offset: int = 0) -> jax.Array:
-    """Flash-style attention: scan over key chunks with online softmax.
+    """Attention, GQA: q [B, Sq, H, D]; k/v [B, Sk, KH, D] with H = G·KH.
 
-    q: [B, Sq, H, D]; k/v: [B, Sk, KH, D] with H = G·KH (GQA).
-    ``q_offset``: absolute position of q[0] (for decode/prefill continuation).
-    Never materializes [Sq, Sk]; peak score block is [B, KH, G, Sq, chunk].
+    ``q_offset``: absolute position of q[0] (for decode/prefill
+    continuation).  Lowered for a TPU, whole-sequence causal self-attention
+    over unpartitioned bf16 operands with 128-aligned heads and length runs
+    the causal flash kernel (``kernels/attention.py``); everything else —
+    windows, offsets, D ≠ Dv, Sq ≠ Sk, a mesh of more than one device, and
+    every other platform — runs ``scan_attention``.
+    """
+    if not _kernel_applies(q, k, v, causal=causal, window=window,
+                           q_offset=q_offset):
+        return scan_attention(q, k, v, causal=causal, window=window,
+                              chunk=chunk, q_offset=q_offset)
+    return jax.lax.platform_dependent(
+        q, k, v,
+        tpu=lambda q, k, v: attn_kernel.causal_attention(q, k, v, False),
+        default=functools.partial(scan_attention, causal=causal,
+                                  chunk=chunk))
+
+
+def _kernel_applies(q, k, v, *, causal, window, q_offset) -> bool:
+    """The shapes and partitioning the flash kernel serves (the platform
+    is decided at lowering, by ``platform_dependent``)."""
+    _, sq, _, d = q.shape
+    sk = k.shape[1]
+    mesh = current_mesh()
+    return (causal and window is None and isinstance(q_offset, int)
+            and q_offset == 0 and sq == sk and sq % attn_kernel.LANE == 0
+            and v.shape[-1] == d and d % attn_kernel.LANE == 0
+            and q.dtype == k.dtype == v.dtype == jnp.bfloat16
+            and (mesh is None or mesh.size == 1))
+
+
+def scan_attention(q, k, v, *, causal: bool = True,
+                   window: Optional[int] = None, chunk: int = 1024,
+                   q_offset: int = 0) -> jax.Array:
+    """Flash-style attention in XLA: scan over key chunks with online
+    softmax.  Arguments as ``blockwise_attention``.  Never materializes
+    [Sq, Sk]; peak score block is [B, KH, G, Sq, chunk].
     """
     B, Sq, H, D = q.shape
     _, Sk, KH, _ = k.shape

@@ -7,6 +7,7 @@ process at a time may load the TPU library, and every test worker imports
 every test file.  Keep these tests in this one file for the same reason.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -14,13 +15,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels import ops
+from repro.kernels import attention, ops
 from repro.kernels.autodiff import rdp_matmul_cols_vjp, rdp_matmul_rows_vjp
 from repro.kernels.fused_ffn import fused_ffn_gated_vjp
 from repro.kernels.rdp_matmul import rdp_matmul_cols, rdp_matmul_rows
 
 CFG = get_config("qwen2_1_5b")
 M, K, N = 4096, CFG.d_model, CFG.d_ff     # tokens per train step, widths
+ATTN_KERNELS = {"attn_causal_fwd", "attn_causal_dkv", "attn_causal_dq"}
 BLOCK = N // CFG.pattern_nb
 DPS = (2, 7)
 
@@ -127,6 +129,26 @@ def test_fused_ffn_gated_grad_compiles(one_chip, no_persistent_cache, dp):
     assert hlo.count("tpu_custom_call") >= 9
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_attention_kernel_compiles(one_chip, no_persistent_cache, grad):
+    """Causal flash attention at the training cell's shape (S 4096, 12
+    query and 2 KV heads of 128): the forward kernel, or all three under
+    ``vjp`` — Mosaic's block and VMEM checks, which interpret mode skips."""
+    S, H, KH, D = M, CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
+    q, k, v = (_sds((1, S, h, D), one_chip) for h in (H, KH, KH))
+
+    def fn(q, k, v):
+        if not grad:
+            return attention.causal_attention(q, k, v, False)
+        out, vjp = jax.vjp(lambda q, k, v: attention.causal_attention(
+            q, k, v, False), q, k, v)
+        return vjp(out)
+
+    hlo = _compile(fn, q, k, v)
+    names = set(re.findall(r"%(attn_causal_\w+?)(?:\.\d+)? = ", hlo))
+    assert names == (ATTN_KERNELS if grad else {"attn_causal_fwd"}), names
+
+
 @pytest.mark.parametrize("entry", ["rdp_up", "rdp_down", "fused_ffn"])
 def test_misaligned_block_raises_clear_error(one_chip, no_persistent_cache,
                                              monkeypatch, entry):
@@ -152,11 +174,12 @@ def test_misaligned_block_raises_clear_error(one_chip, no_persistent_cache,
 def test_step_names_are_stable(one_chip, no_persistent_cache, monkeypatch):
     """What a device trace reads the training step by: its executable is
     ``jit_step``, each compact kernel's instruction carries a name of
-    ``PATTERN_KERNELS`` (the explicit ``pallas_call`` names), and the five
-    named scopes reach the ``op_name`` metadata, forward and backward.
-    One rdp bucket of a small model with lane-wide pattern blocks."""
+    ``PATTERN_KERNELS`` (the explicit ``pallas_call`` names), the causal
+    attention kernels carry their own names, none of them a pattern
+    kernel's, under the ``attn`` scope, and the five named scopes reach the
+    ``op_name`` metadata, forward and backward.  One rdp bucket of a small
+    model with lane-wide pattern blocks and 128-wide heads."""
     import dataclasses
-    import re
     from pathlib import Path
 
     from repro.configs import get_smoke
@@ -171,7 +194,7 @@ def test_step_names_are_stable(one_chip, no_persistent_cache, monkeypatch):
     from trace_layers import SCOPES, scope_of
 
     cfg = dataclasses.replace(get_smoke("qwen2_1_5b"), d_ff=512,
-                              pattern_nb=4, dtype="bfloat16")
+                              pattern_nb=4, head_dim=128, dtype="bfloat16")
     params = materialize(jax.random.PRNGKey(0), init_lm(cfg)[0])
     plan = DropoutPlan(family="rdp", dist=(0.5, 0.5), nb=cfg.pattern_nb,
                        block=cfg.d_ff // cfg.pattern_nb, backend="pallas")
@@ -192,12 +215,18 @@ def test_step_names_are_stable(one_chip, no_persistent_cache, monkeypatch):
         jax.tree.map(sds, tr.state), jax.tree.map(sds, batch),
         sds(jnp.float32(0))).compile().as_text()
     assert hlo.startswith("HloModule jit_step")
-    kernels = {base_name(m) for m in re.findall(
-        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)}
-    assert kernels == {"rdp_matmul_cols", "rdp_matmul_rows",
-                       "rdp_cols_dgrad", "rdp_cols_wgrad",
-                       "rdp_rows_dgrad", "rdp_rows_wgrad"}
-    assert kernels <= PATTERN_KERNELS
+    calls = re.findall(r"%(\S+) = ([^\n]*)custom_call_target="
+                       r"\"tpu_custom_call\"([^\n]*)", hlo)
+    kernels = {base_name(m) for m, _, _ in calls}
+    pattern = {"rdp_matmul_cols", "rdp_matmul_rows", "rdp_cols_dgrad",
+               "rdp_cols_wgrad", "rdp_rows_dgrad", "rdp_rows_wgrad"}
+    assert kernels == pattern | ATTN_KERNELS
+    assert pattern <= PATTERN_KERNELS
+    assert not ATTN_KERNELS & PATTERN_KERNELS
+    for m, a, b in calls:
+        if base_name(m) in ATTN_KERNELS:
+            op = re.search(r'op_name="([^"]*)"', a + b)
+            assert op and scope_of(op.group(1)) == "attn", (m, op)
     paths = re.findall(r'op_name="([^"]*)"', hlo)
     assert {scope_of(p) for p in paths} == set(SCOPES) | {""}
     # the backward pass keeps the scopes too
