@@ -22,6 +22,24 @@ Numbers compared (limits in ``limits/<cell>.json``):
 A leaf is each layer's slice of a stacked parameter.  Leaves whose
 reference gradient is under a thousandth of the median leaf's are left
 out: they move under Adam by rounding alone.
+
+The reference is the cell's (``ctx.reference``, found by the
+configuration's ``model_type``).  A training reference provides, all in
+float32 on the benchmark's own weights:
+
+* ``pattern(conf, dp, bias, seq_len)`` — what ``clipped_grad`` takes as
+  the draw ``(dp, bias)``, the row's positions on the leading axis (the
+  half-batch fault keeps its first half);
+* ``clipped_grad(conf, job, w, batch, keep, low)`` — (loss, gradient, the
+  factor that clips it to the job's global norm); ``low=True`` is the
+  control, the same step one precision below the configuration's;
+* ``adamw(job, w, g, scale, m, v, count, lr)`` — one update, each
+  parameter rounded to its own type;
+* ``cosine_lr(job, step)`` — the job's learning rate.
+
+A new training cell appends its name to the ``workloads`` of
+``train_tokens_per_s`` and of each per-layer metric it reports in
+``BENCHMARK.json``; no other entry changes.
 """
 from __future__ import annotations
 
@@ -33,7 +51,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import gen
-from reference import qwen2 as ref
 
 CHECK_STEPS = 3
 CHECK_SALT = 0xC4EC     # keys the checked steps' draw apart from the rows'
@@ -146,22 +163,26 @@ def _rows(mix, seed, vocab):
     return feed
 
 
+def make_plan(model, cfg, mix):
+    """The mix's dropout plan over the adapter's blocking (the identity
+    plan where the mix has none)."""
+    from repro.core.plan import build_plan, identity_plan
+    nb, block = model.plan_blocks(cfg)
+    p = mix.get("plan")
+    if not p:
+        return identity_plan(nb=nb, block=block)
+    return build_plan(p["family"], p["rate"], nb=nb, dp_max=p["dp_max"],
+                      block=block, backend=p["backend"], seed=p["seed"])
+
+
 def build(ctx):
     """The trainer, its data feed and the plan, from the cell's files."""
-    from repro.core.plan import build_plan, identity_plan
     from repro.optim.optimizers import AdamW
     from repro.train.distributed import DistributedTrainer, TrainerConfig
     conf, mix, model = ctx.conf, ctx.mix, ctx.model
     cfg = model.program_config(conf, conf["program"])
     job = mix["job"]
-    p = mix.get("plan")
-    if p:
-        plan = build_plan(p["family"], p["rate"], nb=cfg.pattern_nb,
-                          dp_max=p["dp_max"],
-                          block=cfg.d_ff // cfg.pattern_nb,
-                          backend=p["backend"], seed=p["seed"])
-    else:
-        plan = identity_plan(nb=cfg.pattern_nb)
+    plan = make_plan(model, cfg, mix)
     weights = model.make_weights(conf, jax.random.key(gen.key32(ctx.seed)))
     model.check_layout(cfg, weights)
     tcfg = TrainerConfig(steps=job["total_steps"], base_lr=job["lr"],
@@ -282,10 +303,11 @@ def compare(got: dict, want: dict) -> tuple:
 def reference(ctx, buckets, low: bool = False, half: bool = False) -> dict:
     """The reference's first steps from the run's weights and rows:
     losses, the first clipped gradient's leaf norms, and each leaf's
-    change over the steps.  ``low`` computes in float8 (the control);
-    ``half`` keeps only the first half of each step's rows (a fault)."""
-    conf, mix = ctx.conf, ctx.mix
-    job, nb = mix["job"], conf["program"]["pattern_nb"]
+    change over the steps.  ``low`` is the reference's control, one
+    precision lower; ``half`` keeps only the first half of each step's
+    rows (a fault)."""
+    conf, mix, ref = ctx.conf, ctx.mix, ctx.reference
+    job = mix["job"]
     rows = _rows(mix, ctx.seed, conf["vocab_size"])
 
     def start():
@@ -305,8 +327,7 @@ def reference(ctx, buckets, low: bool = False, half: bool = False) -> dict:
     step = jax.jit(step, donate_argnums=(0, 1, 2))
     losses, grad = [], None
     for k, (dp, b) in enumerate(buckets):
-        keep = ref.keep_vector(conf["intermediate_size"], nb, dp, b)
-        keep = jnp.broadcast_to(keep, (mix["seq_len"], keep.shape[0]))
+        keep = ref.pattern(conf, dp, b, mix["seq_len"])
         batch = rows(k)
         if half:
             n = mix["seq_len"] // 2

@@ -30,6 +30,23 @@ def dims(conf: dict) -> F.Dims:
                   vocab=conf["vocab_size"])
 
 
+def train_step_flops(conf: dict, tokens: int, seq_len: int,
+                     dp: int = 1) -> float:
+    """Operations one training step requires, the FFN at 1/dp."""
+    return F.train_step_flops(dims(conf), tokens, seq_len, dp)
+
+
+def tiny(conf: dict) -> dict:
+    """``conf`` with every width shrunk, at a size a CPU test can hold."""
+    c = dict(conf, hidden_size=64, intermediate_size=256,
+             num_attention_heads=4, num_key_value_heads=2,
+             num_hidden_layers=2, vocab_size=512,
+             initializer_range=0.2)   # so the layers, not the tied
+                                      # embedding's echo, set the logits
+    c["program"] = {"pattern_nb": 4}
+    return c
+
+
 def dtype(conf: dict):
     """The type the weights are served in."""
     return jnp.dtype(conf["torch_dtype"])
@@ -102,6 +119,11 @@ def program_config(conf: dict, program: dict):
         rope_theta=float(conf["rope_theta"]), tie_embeddings=True,
         norm_eps=float(conf["rms_norm_eps"]), dtype=conf["torch_dtype"],
         pattern_nb=int(program["pattern_nb"]))
+
+
+def plan_blocks(cfg) -> tuple:
+    """(nb, block): the FFN's hidden units in ``pattern_nb`` blocks."""
+    return cfg.pattern_nb, cfg.d_ff // cfg.pattern_nb
 
 
 def check_layout(cfg, weights) -> None:

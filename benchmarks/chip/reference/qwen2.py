@@ -138,6 +138,14 @@ def keep_vector(d_ff: int, nb: int, dp: int, bias: int):
     return jnp.where(block % dp == bias, float(dp), 0.0)
 
 
+def pattern(conf, dp: int, bias: int, seq_len: int):
+    """``keep`` for ``clipped_grad`` under the draw (dp, bias): the FFN
+    multipliers of every position of a ``seq_len`` row, [seq_len, d_ff]."""
+    keep = keep_vector(conf["intermediate_size"],
+                       conf["program"]["pattern_nb"], dp, bias)
+    return jnp.broadcast_to(keep, (seq_len, keep.shape[0]))
+
+
 def trunk(conf, w, tokens, keep, low=False):
     """Final-normed hidden states [B,S,d] of ``tokens`` [B,S]; ``keep``
     [S, d_ff] gives each position's FFN multipliers."""

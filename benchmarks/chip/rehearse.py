@@ -1,12 +1,13 @@
 """Compile a cell's executables for a described TPU v5e, without the chip.
 
     JAX_PLATFORMS=cpu PYTHONPATH=src python3 benchmarks/chip/rehearse.py \\
-        train --layers 8 [--dp 1 2]
+        train [--config qwen2-1.5b-train] --layers 8 [--dp 1 2]
 
 Prints, per executable, what ``memory_analysis`` gives (arguments,
 temporaries, outputs, aliased bytes) and the count of Pallas kernels
-(``tpu_custom_call``).  This sizes the training depth before any chip time
-is spent; it measures no time.
+(``tpu_custom_call``).  The configuration ``configs/<config>.json`` and
+its adapter ``models/<model_type>.py`` are found by name.  This sizes the
+training depth before any chip time is spent; it measures no time.
 """
 from __future__ import annotations
 
@@ -63,10 +64,11 @@ def train(args, dev) -> None:
     from repro.train.distributed import TrainState, state_shardings
     from repro.train.train_step import make_train_step
     sys.path.insert(0, str(BENCH))
-    from models import qwen2
-    conf = json.load(open(BENCH / "configs" / "qwen2-1.5b-train.json"))
+    import run as R
+    conf = R.load_json(BENCH / "configs" / f"{args.config}.json")
     conf = dict(conf, num_hidden_layers=args.layers)
-    cfg = qwen2.program_config(conf, conf["program"])
+    model = R.load_module(BENCH / "models" / f"{conf['model_type']}.py")
+    cfg = model.program_config(conf, conf["program"])
     mesh = Mesh([[dev]], ("data", "model"))
     rules = PROFILES["tp"]
     params, axes = init_lm(cfg)
@@ -90,7 +92,8 @@ def train(args, dev) -> None:
     batch = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
         batch, bsh)
-    plan = build_plan("rdp", 0.5, nb=cfg.pattern_nb, dp_max=8,
+    nb, block = model.plan_blocks(cfg)
+    plan = build_plan("rdp", 0.5, nb=nb, dp_max=8, block=block,
                       backend="pallas", seed=0)
     from repro.core import plan as plan_mod
     for dp in args.dp:
@@ -117,6 +120,7 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     sub = ap.add_subparsers(dest="what", required=True)
     t = sub.add_parser("train")
+    t.add_argument("--config", default="qwen2-1.5b-train")
     t.add_argument("--layers", type=int, default=8)
     t.add_argument("--seq", type=int, default=4096)
     t.add_argument("--dp", type=int, nargs="+", default=[1, 2])

@@ -5,10 +5,29 @@
 
 Everything is found by name from ``BENCHMARK.json``: the cell's
 configuration file, its mix ``traffic/<traffic>.json``, the model adapter
-``models/<model_type>.py``, the driver ``drivers/<kind>.py`` the mix
-names, the limits ``limits/<cell>.json``, and, with ``--trace 1``, one
-reader ``metrics/<metric>.py`` per per-layer metric.  Nothing here knows a
-cell by its name.
+``models/<model_type>.py`` and the plain reference
+``reference/<model_type>.py`` (``model_type`` from the configuration), the
+driver ``drivers/<kind>.py`` the mix names, the limits
+``limits/<cell>.json``, and, with ``--trace 1``, one reader
+``metrics/<metric>.py`` per per-layer metric.  Nothing here knows a cell
+or a model by its name.
+
+A model adapter provides:
+
+* ``make_weights(conf, key)`` — every weight from the key, in one jitted
+  call, in the program's parameter tree and the configuration's types;
+* ``program_config(conf, program)`` — the program's ``ModelConfig``;
+* ``check_layout(cfg, weights)`` — raises unless the weights are exactly
+  the program's parameter tree;
+* ``plan_blocks(cfg)`` — ``(nb, block)``: the pattern blocks of the
+  dropped dimension and the units in each, for the program's plan;
+* ``train_step_flops(conf, tokens, seq_len, dp)`` — operations one
+  training step requires under a draw of period ``dp`` (``train.mfu_pct``);
+* ``dims(conf)`` — ``flops.Dims`` for the compact FFN kernels' counts
+  (only cells that report ``train.ffn_roofline``);
+* ``tiny(conf)`` — the configuration at a size a CPU test can hold.
+
+What a training reference provides is in ``drivers/train.py``.
 
 The last line of stdout is one JSON object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` and, traced, ``breakdown``, then
@@ -63,15 +82,19 @@ class Cell:
         self.entry = cells[name]
         self.name = name
         confs = {c["name"]: c for c in bench["configs"]}
-        # ``given`` replaces files (the tests run cells at small sizes)
+        # ``given`` replaces files (the tests run cells at small sizes,
+        # or against a stand-in reference)
         self.conf = given.get("conf") or load_json(
             ROOT / confs[self.entry["config"]]["file"])
         self.mix = given.get("mix") or load_json(
             BENCH / "traffic" / f"{self.entry['traffic']}.json")
         self.limits = given.get("limits") or load_json(
             BENCH / "limits" / f"{name}.json")
-        self.model = load_module(BENCH / "models"
-                                 / f"{self.conf['model_type']}.py")
+        kind = self.conf["model_type"]
+        self.model = given.get("model") or load_module(
+            BENCH / "models" / f"{kind}.py")
+        self.reference = given.get("reference") or load_module(
+            BENCH / "reference" / f"{kind}.py")
         self.driver = load_module(BENCH / "drivers"
                                   / f"{self.mix['kind']}.py")
         self.end_to_end = [m for m in bench["end_to_end"]
@@ -92,7 +115,8 @@ class Context:
             cell, seed, seconds, trace)
         # calibration only: also read the control and the planted faults
         self.control = control
-        self.conf, self.mix, self.model = cell.conf, cell.mix, cell.model
+        self.conf, self.mix = cell.conf, cell.mix
+        self.model, self.reference = cell.model, cell.reference
         self.counters: dict = {}
         self.t_window = None
         self.memory_peak = self.memory_limit = None

@@ -4,6 +4,7 @@ shapes (d 64, 4 query and 2 key/value heads of 16, d_ff 256, vocab 512,
 import pytest
 
 import flops as F
+import run as R
 
 SMOKE = F.Dims(layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
                d_ff=256, vocab=512)
@@ -41,3 +42,12 @@ def test_kernel_work_by_hand():
     assert ops == 3 * 32 * 2 * 6 * 64 * 64
     x, h, w = 32 * 64 * 2, 32 * 64 * 2, 64 * 64 * 2
     assert nbytes == 2 * 9 * (x + h + w)
+
+
+@pytest.mark.parametrize("dp", [1, 2, 5, 7])
+def test_adapter_counts_the_step_as_flops_does(dp):
+    # train.mfu_pct reads the adapter's count; it must be flops.py's
+    conf = R.load_json(R.BENCH / "configs" / "qwen2-1.5b-train.json")
+    model = R.load_module(R.BENCH / "models" / f"{conf['model_type']}.py")
+    assert model.train_step_flops(conf, 4096, 4096, dp) == \
+        F.train_step_flops(model.dims(conf), 4096, 4096, dp)

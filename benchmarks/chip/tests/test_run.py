@@ -47,6 +47,13 @@ def test_benchmark_files_alone_exit_nonzero(tmp_path):
     assert _no_result(p.stdout)
 
 
+# what run.py's and drivers/train.py's docstrings ask of an adapter and
+# of a training reference
+ADAPTER = ("make_weights", "program_config", "check_layout", "plan_blocks",
+           "train_step_flops", "dims", "tiny")
+TRAIN_REFERENCE = ("pattern", "clipped_grad", "adamw", "cosine_lr")
+
+
 def test_every_name_resolves_to_files():
     import run as R
     bench = R.load_json(ROOT / "BENCHMARK.json")
@@ -55,5 +62,12 @@ def test_every_name_resolves_to_files():
         assert cell.end_to_end and cell.per_layer
         for m in cell.per_layer:
             assert (BENCH / "metrics" / f"{m['name']}.py").exists()
+        assert all(callable(getattr(cell.model, f, None)) for f in ADAPTER)
+        if cell.mix["kind"] == "train":
+            assert all(callable(getattr(cell.reference, f, None))
+                       for f in TRAIN_REFERENCE)
     for c in bench["configs"]:
         assert (ROOT / c["file"]).exists()
+        kind = R.load_json(ROOT / c["file"])["model_type"]
+        assert (BENCH / "models" / f"{kind}.py").exists()
+        assert (BENCH / "reference" / f"{kind}.py").exists()

@@ -1,5 +1,5 @@
-"""Cells at a size a CPU test can hold: the published Qwen2 block with
-every width shrunk, and short mixes."""
+"""Cells at a size a CPU test can hold: each configuration shrunk by its
+adapter's ``tiny``, and short mixes."""
 import copy
 import json
 from pathlib import Path
@@ -8,13 +8,9 @@ BENCH = Path(__file__).resolve().parents[1]
 
 
 def conf(name: str) -> dict:
+    import run as R
     c = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    c.update(hidden_size=64, intermediate_size=256, num_attention_heads=4,
-             num_key_value_heads=2, num_hidden_layers=2, vocab_size=512,
-             initializer_range=0.2)   # so the layers, not the tied
-                                      # embedding's echo, set the logits
-    c["program"] = {"pattern_nb": 4}
-    return c
+    return R.load_module(BENCH / "models" / f"{c['model_type']}.py").tiny(c)
 
 
 def mix(name: str) -> dict:
